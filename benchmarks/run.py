@@ -735,8 +735,7 @@ def fleet_engine(smoke=None):
                            "goodput_share": m["goodput_share"]}
                        for t, m in meta["per_tenant"].items()},
         "compiles_post_warmup": cgate.count,
-        "compile_gate": {"limit": cgate.limit,
-                         "available": cgate.available}}
+        "compile_gate": {"limit": cgate.limit}}
     # hard gates: full runs must clear the ISSUE's 50x floor against
     # the committed heap numbers; smoke runs (CI boxes, tiny horizon)
     # gate on an absolute events/s floor instead
@@ -892,7 +891,7 @@ def online_engine(smoke=None):
             rep, us_i = _timed(eng.ingest, delta, **gbt_kw)
             (reg_s, full), us_s = _timed(scratch_fit)
         epoch_compiles.append(cr.count)
-        if compile_budget is None and cr.available:
+        if compile_budget is None:
             compile_budget = cr.count + 2
         inc_wall += us_i / 1e6
         scratch_wall += us_s / 1e6
@@ -1597,6 +1596,8 @@ def main() -> None:
                    metavar="B1,B2,...")
     p.add_argument("--reps", type=int, default=None)
     args = p.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     OPTS.update(smoke=args.smoke, grid_ii=args.grid_ii,
                 grid_oo=args.grid_oo, grid_bb=args.grid_bb, reps=args.reps)
     names = args.names

@@ -1,10 +1,10 @@
 """Real wall-clock benchmarking of the JAX serving engine.
 
 The paper's "custom inference benchmarking framework": sweep (ii, oo, bb),
-run each combination ``reps`` times, record tokens/sec.  On this CPU
-container it runs tiny smoke-size models (the numbers are real measured
-throughput of the actual engine); on TPU the same harness benchmarks the
-full configs.  Output rows feed the same ALA pipeline as simulator data —
+run each combination ``reps`` times, record tokens/sec.  It builds the
+smoke-size configs; each row's ``acc`` names the device kind that ran it
+(``jax.devices()[0].device_kind``), so CPU rows never pass for chip
+rows.  Output rows feed the same ALA pipeline as simulator data —
 the framework is agnostic to where thpt came from.
 """
 from __future__ import annotations
@@ -39,11 +39,12 @@ def measure_arch(arch: str, grid_ii: Optional[Sequence[int]] = None,
     model = Model(cfg)
     params = model.init(jax.random.key(seed))
     engine = ServingEngine(model, params)
+    acc = jax.devices()[0].device_kind
     rows: List[Dict] = []
     for ii, oo, bb in itertools.product(grid_ii, grid_oo, grid_bb):
         for r in engine.measure_throughput(ii, oo, bb, reps=reps,
                                            seed=seed):
-            rows.append(dict(model=arch, acc="cpu-host", acc_count=1,
+            rows.append(dict(model=arch, acc=acc, acc_count=1,
                              back="repro-jax", prec="fp32", mode="serve",
                              ii=r["ii"], oo=r["oo"], bb=r["bb"],
                              thpt=r["thpt"]))

@@ -20,6 +20,9 @@ import numpy as np
 
 _LM_ITERS = 60
 _MU0 = 1e-2
+# float32 matmuls run as one bf16 pass on the TPU by default; the solve
+# needs full float32 there to agree with the CPU (a no-op on the CPU)
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _residuals(theta, x, y, w):
@@ -50,7 +53,8 @@ def _solve3(A, b):
     c22 = A[0, 0] * A[1, 1] - A[0, 1] * A[1, 0]
     adj = jnp.array([[c00, c10, c20], [c01, c11, c21], [c02, c12, c22]])
     safe = jnp.where(det == 0, 1.0, det)
-    return jnp.where(det == 0, jnp.zeros(3), (adj @ b) / safe)
+    return jnp.where(det == 0, jnp.zeros(3),
+                     jnp.matmul(adj, b, precision=_HIGHEST) / safe)
 
 
 def _lm_step(theta, mu, x, y, w):
@@ -59,8 +63,8 @@ def _lm_step(theta, mu, x, y, w):
     a, b = theta[0], theta[1]
     e = jnp.exp(-b * x)
     J = jnp.stack([-e * w, a * x * e * w, jnp.ones_like(x) * w], axis=1)
-    JtJ = J.T @ J
-    Jtr = J.T @ r
+    JtJ = jnp.matmul(J.T, J, precision=_HIGHEST)
+    Jtr = jnp.matmul(J.T, r, precision=_HIGHEST)
     loss = jnp.sum(r * r)
 
     def solve(m):

@@ -363,7 +363,9 @@ def _make_bank_kernel():
             num_segments=Q * F * B).reshape(Q, F, B)
         nrm = jnp.sqrt((counts * counts).sum(axis=-1, keepdims=True))
         unit = counts / jnp.maximum(nrm, 1e-30)
-        sim = jnp.einsum("qfb,sfb->qsf", unit, s_unit)
+        # full float32 on the TPU too, where the default is one bf16 pass
+        sim = jnp.einsum("qfb,sfb->qsf", unit, s_unit,
+                         precision=jax.lax.Precision.HIGHEST)
         return (1.0 - sim).mean(axis=-1)                       # (Q, S)
 
     return kernel

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 from repro.models.config import ModelConfig
 
 
@@ -129,7 +128,7 @@ def moe_ffn_ep(cfg: ModelConfig, params, x, policy):
     else:
         # small-batch serving (e.g. long-context bb=1): shard tokens on seq
         x_spec = P(None, batch_spec, None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, P(), P(model_ax), P(model_ax), P(model_ax)),
         out_specs=(x_spec, P()),

@@ -1,3 +1,12 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels, each with a jnp oracle (``ref.py``) and a jitted
+wrapper (``ops.py``) that picks the path by platform."""
+from __future__ import annotations
+
+import jax
+
+
+def dispatch_mode(force: str | None = None) -> str:
+    """The path an ``ops`` wrapper takes: ``force`` when given ("kernel",
+    "interpret" or "ref"), else the Pallas kernel on the TPU and the jnp
+    oracle elsewhere.  Interpret mode is never chosen unforced."""
+    return force or ("kernel" if jax.default_backend() == "tpu" else "ref")

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch_mode
 from repro.kernels.decode_attention.kernel import decode_attention_grouped
 from repro.kernels.decode_attention.ref import decode_attention_ref
 
@@ -20,7 +21,7 @@ def decode_attention(q, k, v, pos, scale: float | None = None,
     qg = q.reshape(b, kv, g, dh)
     kh = k.swapaxes(1, 2)      # (B, KV, T, Dh)
     vh = v.swapaxes(1, 2)
-    mode = force or ("kernel" if jax.default_backend() == "tpu" else "ref")
+    mode = dispatch_mode(force)
     if mode == "ref":
         out = decode_attention_ref(qg, kh, vh, pos, scale=scale)
     else:

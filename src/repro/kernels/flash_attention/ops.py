@@ -11,6 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch_mode
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
 from repro.kernels.flash_attention.ref import attention_ref
 
@@ -21,7 +22,7 @@ def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
                     block_q: int = 512, block_k: int = 512,
                     force: str | None = None):
     """q: (B, S, H, Dh); k/v: (B, S, KV, Dh) -> (B, S, H, Dh)."""
-    mode = force or ("kernel" if jax.default_backend() == "tpu" else "ref")
+    mode = dispatch_mode(force)
     qh = q.swapaxes(1, 2)
     kh = k.swapaxes(1, 2)
     vh = v.swapaxes(1, 2)

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch_mode
 from repro.kernels.gbt_hist.kernel import gbt_hist as gbt_hist_kernel
 from repro.kernels.gbt_hist.ref import gbt_hist_ref
 
@@ -15,7 +16,7 @@ from repro.kernels.gbt_hist.ref import gbt_hist_ref
 def build_histograms(bins, grad, hess, n_bins: int, block_f: int = 8,
                      block_n: int = 512, force: str | None = None):
     """bins: (n, f) int32; grad/hess: (n,) -> (f, n_bins, 2) fp32."""
-    mode = force or ("kernel" if jax.default_backend() == "tpu" else "ref")
+    mode = dispatch_mode(force)
     if mode == "ref":
         return gbt_hist_ref(bins, grad, hess, n_bins)
     n, f = bins.shape

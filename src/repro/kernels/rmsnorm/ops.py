@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch_mode
 from repro.kernels.rmsnorm.kernel import rmsnorm_2d
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
 
@@ -18,7 +19,7 @@ def rmsnorm(x, scale, eps: float = 1e-5, block_rows: int = 256,
     ``force``: None (auto: kernel on TPU, interpret-kernel nowhere — oracle
     elsewhere), "kernel", "interpret", or "ref".
     """
-    mode = force or ("kernel" if jax.default_backend() == "tpu" else "ref")
+    mode = dispatch_mode(force)
     if mode == "ref":
         return rmsnorm_ref(x, scale, eps)
     lead = x.shape[:-1]

@@ -155,10 +155,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
             t_lower = time.time() - t0
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
-        from repro.distributed.compat import cost_analysis_dict
-
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis() or {}
         rec.update(
             status="ok",
             lower_s=round(t_lower, 2),

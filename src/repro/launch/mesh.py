@@ -10,17 +10,24 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    # Auto axes: sharding constraints inside jit may name any mesh axis
+    # (make_mesh defaults to Explicit axes, which reject them)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
-    """Tiny mesh over the real host devices (tests / CPU demos)."""
+    """(data, model) mesh over the devices of this host."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def data_axes_of(mesh) -> tuple:

@@ -36,7 +36,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-import warnings
 from typing import Iterator, Optional
 
 import numpy as np
@@ -55,12 +54,11 @@ class CompileBudgetExceeded(AssertionError):
 
 
 class _CompileCounter:
-    __slots__ = ("n_compiles", "n_lowerings", "available")
+    __slots__ = ("n_compiles", "n_lowerings")
 
     def __init__(self) -> None:
         self.n_compiles = 0
         self.n_lowerings = 0
-        self.available = False
 
 
 _COUNTER: Optional[_CompileCounter] = None
@@ -69,20 +67,17 @@ _COUNTER: Optional[_CompileCounter] = None
 def _get_counter() -> _CompileCounter:
     global _COUNTER
     if _COUNTER is None:
+        from jax import monitoring
+
         counter = _CompileCounter()
-        try:
-            from jax import monitoring
 
-            def _on_duration(key: str, duration: float, **kw) -> None:
-                if key == _COMPILE_EVENT:
-                    counter.n_compiles += 1
-                elif key == _LOWERING_EVENT:
-                    counter.n_lowerings += 1
+        def _on_duration(key: str, duration: float, **kw) -> None:
+            if key == _COMPILE_EVENT:
+                counter.n_compiles += 1
+            elif key == _LOWERING_EVENT:
+                counter.n_lowerings += 1
 
-            monitoring.register_event_duration_secs_listener(_on_duration)
-            counter.available = True
-        except Exception:            # jax absent, or the API moved
-            counter.available = False
+        monitoring.register_event_duration_secs_listener(_on_duration)
         _COUNTER = counter
     return _COUNTER
 
@@ -94,7 +89,6 @@ class CompileReport:
     label: str = ""
     n_compiles: int = 0            # backend compiles (cache misses)
     n_lowerings: int = 0           # jaxpr->MLIR lowerings
-    available: bool = True         # jax.monitoring delivered events
 
     @property
     def count(self) -> int:
@@ -111,25 +105,16 @@ def assert_max_compiles(n: Optional[int],
     Yields a :class:`CompileReport` that fills in on exit; raises
     :class:`CompileBudgetExceeded` when the block compiled (or
     re-lowered) more than ``n`` programs.  ``n=None`` counts without
-    asserting.  When ``jax.monitoring`` is unavailable the gate
-    degrades to a counted no-op with ``report.available = False`` and
-    a warning — a missing monitoring API must not turn a perf gate
-    into a hard import failure on exotic jax builds.
+    asserting.
     """
     counter = _get_counter()
-    report = CompileReport(limit=n, label=label,
-                           available=counter.available)
+    report = CompileReport(limit=n, label=label)
     c0, l0 = counter.n_compiles, counter.n_lowerings
     try:
         yield report
     finally:
         report.n_compiles = counter.n_compiles - c0
         report.n_lowerings = counter.n_lowerings - l0
-    if not counter.available:
-        warnings.warn("assert_max_compiles: jax.monitoring unavailable; "
-                      "compile gate not enforced", RuntimeWarning,
-                      stacklevel=2)
-        return
     if n is not None and report.count > n:
         where = f" [{label}]" if label else ""
         raise CompileBudgetExceeded(

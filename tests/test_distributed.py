@@ -27,11 +27,12 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
 from repro.distributed.sharding import ShardingPolicy, use_policy
 from repro.distributed.ep_moe import ep_available, moe_ffn_ep
+from repro.launch.mesh import make_host_mesh
 from repro.models import moe as moe_mod
 
 # generous capacity so no tokens drop -> EP and GSPMD paths must agree
 cfg = get_smoke_config("phi3.5-moe-42b-a6.6b").scaled(capacity_factor=8.0)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(model=4)
 policy = ShardingPolicy(mesh, data_axes=("data",), model_axes=("model",))
 assert ep_available(cfg, policy)
 
@@ -68,13 +69,14 @@ import jax, jax.numpy as jnp
 from repro.configs import get_smoke_config
 from repro.configs.shapes import ShapeSpec
 from repro.distributed.sharding import ShardingPolicy, use_policy
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_step
 from repro.models.transformer import Model
 
 # 6 heads on a 4-wide model axis -> not divisible -> CP fallback engages
 cfg = get_smoke_config("llama3.2-3b").scaled(
     n_heads=6, n_kv_heads=2, param_dtype=jnp.bfloat16)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(model=4)
 policy = ShardingPolicy(mesh, data_axes=("data",), serving=True,
                         serving_2d=False, cp_replicate_weights=True)
 shape = ShapeSpec("p", seq_len=64, global_batch=4, kind="prefill")
@@ -83,8 +85,7 @@ step, in_sh, out_sh, args = build_step(model, policy, shape)
 with use_policy(policy):
     compiled = jax.jit(step, in_shardings=in_sh,
                        out_shardings=out_sh).lower(*args).compile()
-from repro.distributed.compat import cost_analysis_dict
-print("CP_COMPILE_OK", cost_analysis_dict(compiled).get("flops"))
+print("CP_COMPILE_OK", compiled.cost_analysis().get("flops"))
 """
 
 
@@ -98,11 +99,12 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
 from repro.configs.shapes import ShapeSpec
 from repro.distributed.sharding import ShardingPolicy, use_policy
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_serve_step
 from repro.models.transformer import Model
 
 cfg = get_smoke_config("qwen2.5-32b").scaled(param_dtype=jnp.bfloat16)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(model=4)
 policy = ShardingPolicy(mesh, data_axes=("data",), serving=True,
                         serving_2d=False)
 shape = ShapeSpec("d", seq_len=64, global_batch=8, kind="decode")
