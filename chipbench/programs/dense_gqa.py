@@ -15,6 +15,11 @@ from repro.models.config import ModelConfig
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 
+# the CPU tests' size: every flag of the configuration as published
+SMALL = dict(hidden_size=64, intermediate_size=160, num_attention_heads=4,
+             num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
+             vocab_size=300)
+
 
 def model_config(conf: dict) -> ModelConfig:
     c, arch = conf["config"], conf["architecture"]

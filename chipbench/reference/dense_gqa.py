@@ -213,6 +213,20 @@ def _hidden(spec: Spec, w: dict, tokens, dtype, kind=None):
     return _rmsnorm(x, w["final_norm"], spec.eps)
 
 
+def logits(spec: Spec, w: dict, tokens, dtype):
+    """(T, vocab) logits at every position of ``tokens``, the full
+    forward pass in ``dtype``."""
+    head = (w["embed"].T if spec.tied else w["head"])[:, :spec.vocab]
+    return _hidden(spec, w, tokens, dtype) @ head.astype(dtype)
+
+
+def uncounted_params(spec: Spec) -> int:
+    """The final norm and QK-norm, which ``ModelConfig.param_count()``
+    leaves out."""
+    return spec.d_model + (2 * spec.layers * spec.d_head if spec.qk_norm
+                           else 0)
+
+
 def _head_slices(spec: Spec, w: dict):
     """(start, stop) of the head's column blocks over the true vocabulary."""
     size = -(-spec.vocab // HEAD_BLOCKS)

@@ -16,15 +16,49 @@ asks for.  One process per run:
   5. the window: batches drawn from the traffic file and the seed, handed to
      ``generate`` back to back (closed loop) for ``--seconds`` and to the end
      of the mix's last round; with ``--trace 1`` under the profiler;
-  6. the peak of device memory, the metrics (each read by its own file,
-     ``chipbench/metrics/<name>.py``), then the comparison that decides
-     ``correct`` (``chipbench/check.py``), outside set-up and window;
+  6. the peak of device memory; with ``--trace 1`` the trace reduced to
+     device time per batch (``chipbench/trace_reduce.py``) and, over the
+     programs compiled again after the window, split by layer scope and
+     engine phase (``chipbench/scopes.py``); the metrics (each read by its
+     own file, ``chipbench/metrics/<name>.py``), then the comparison that
+     decides ``correct`` (``chipbench/check.py``), outside set-up and window;
   7. the result: its checks on the last lines of standard error, and one
      JSON line last on standard output.
 
 Everything that belongs to one configuration, traffic mix, cell or
 per-layer metric is a file of its own under ``chipbench/``, found by the
-names in ``BENCHMARK.json``.
+names in ``BENCHMARK.json``.  A configuration file names three modules by
+``program``, ``reference`` and ``counts``; a new model family joins by new
+modules under those names, which provide:
+
+  ``chipbench/programs/<name>.py``, the program under test:
+    ``model_config(conf) -> ModelConfig`` of ``repro.models``;
+    ``embedding_rows(cfg) -> int``, the embedding rows the program holds;
+    ``program_params(model, w) -> params``, the program's parameter tree
+    over the reference's weights ``w``, raising where the layouts differ;
+    ``embed_bytes(params) -> int``, the embedding table's bytes;
+    ``SMALL``, the configuration keys and values of the CPU tests' size;
+  ``chipbench/reference/<name>.py``, the plain reference:
+    ``Spec``, a hashable dataclass with at least ``vocab``;
+    ``spec_from_config(conf, rows) -> Spec``;
+    ``init_weights(spec, key, dtype=bfloat16) -> w``, jittable;
+    ``gaps(spec, w, tokens, served, start) -> (best, at)``, jitted with
+    ``spec`` and ``start`` static, and ``control_gaps(spec, w, tokens,
+    served, start, kind)`` likewise (``chipbench/check.py``);
+    ``logits(spec, w, tokens, dtype) -> (T, vocab)``, the full forward
+    pass over one sequence;
+    ``uncounted_params(spec) -> int``, the parameters of ``w`` that
+    ``ModelConfig.param_count()`` leaves out;
+  ``chipbench/counts/<name>.py``, what a call needs, from the shapes:
+    ``prefill_flops(spec, batch, prompt_len)``;
+    ``decode_flops(spec, batch, start, steps)`` for the steps writing
+    positions ``start .. start + steps - 1``;
+    ``decode_bytes(spec, batch, start, steps, weight_bytes, embed_bytes)``.
+
+A reader sees each batch of the window as ``rec["batches"][i]``, a
+``stats.BatchRecord``; its ``counters`` hold the numbers that the
+program's ``generate`` reported for that call in its result's
+``counters``, if any.
 """
 from __future__ import annotations
 
@@ -47,7 +81,8 @@ for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-from chipbench import check, gate, generator, stats  # noqa: E402
+from chipbench import (check, gate, generator, scopes, stats,  # noqa: E402
+                       trace_reduce)
 
 BENCH_DIR = ROOT / "chipbench"
 CACHE_DIR = ROOT / ".jax_cache"
@@ -177,18 +212,18 @@ def run_window(system: System, batches: generator.Batches, seconds: float,
         records.append(stats.BatchRecord(
             batch=prompts.shape[0], prompt_len=ii, output_len=oo,
             start_s=t0 - t_start, end_s=t1 - t_start,
-            prefill_s=res.prefill_s, decode_s=res.decode_s))
+            prefill_s=res.prefill_s, decode_s=res.decode_s,
+            counters=dict(getattr(res, "counters", {}))))
         served.append(check.Served(prompts=prompts, tokens=res.tokens))
         i += 1
         if t1 - t_start >= seconds and i % batches.round_len == 0:
             return records, served
 
 
-def traced_window(system, batches, seconds, chips):
-    """The window under the profiler, reduced to device busy time, the
-    programs each batch ran and the longest idle gaps."""
+def traced_window(system, batches, seconds):
+    """The window under the profiler.  Returns (records, served, the
+    trace's planes)."""
     import jax
-    from chipbench import trace_reduce
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     tmp = tempfile.mkdtemp(prefix="chipbench-trace-")
@@ -198,10 +233,10 @@ def traced_window(system, batches, seconds, chips):
                 records, served = run_window(
                     system, batches, seconds,
                     annotate=jax.profiler.TraceAnnotation)
-        reduced = trace_reduce.reduce_dir(tmp, chips)
+        planes = trace_reduce.load_planes(tmp)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
-    return records, served, reduced
+    return records, served, planes
 
 
 # -- the comparison -------------------------------------------------------------
@@ -234,6 +269,7 @@ class Measured:
     setup_s: float
     compiles_in_window: int
     per_layer_unread: list
+    trace: dict = None       # the reduced trace of a traced run
 
 
 def measure(cell: Cell, seed: int, seconds: float, trace: bool,
@@ -248,14 +284,16 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
     setup_s = time.perf_counter() - t_begin
     before = compiles.compiles
     if trace:
-        records, served, reduced = traced_window(system, batches, seconds,
-                                                 cell.chips)
+        records, served, planes = traced_window(system, batches, seconds)
     else:
         records, served = run_window(system, batches, seconds)
     in_window = compiles.compiles - before
     device = dict(device, memory_peak_bytes=gate.memory_peak_bytes(cell.chips))
-    unread = []
+    unread, reduced = [], None
     if trace:
+        reduced = trace_reduce.reduce_planes(planes, cell.chips)
+        reduced["scopes"] = scopes.reduce_scopes(
+            planes, scopes.program_maps(system.engine, records))
         metrics = read_metrics(cell.per_layer, metric_record(
             cell, spec, system, records, reduced, device["kind"]))
         unread = [m["name"] for m in cell.per_layer
@@ -269,7 +307,7 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
     return Measured(spec=spec, weights=weights, records=records,
                     served=served, metrics=metrics, device=device,
                     setup_s=setup_s, compiles_in_window=in_window,
-                    per_layer_unread=unread)
+                    per_layer_unread=unread, trace=reduced)
 
 
 def judge(cell: Cell, m: Measured, seed: int, control: str = None):
@@ -360,6 +398,10 @@ def main(argv=None, root: pathlib.Path = None) -> int:
                      "control": args.control,
                      "cache_hits": compiles.cache_hits,
                      "cache_misses": compiles.cache_misses}
+    if m.trace is not None:
+        line["setup"]["device_lag_s"] = m.trace["device_lag_s"]
+        line["setup"]["split"] = scopes.summary(
+            m.records, m.trace["batches"], m.trace["scopes"])
     line["checks"] = {k: {"value": v, "limit": lim}
                       for k, (v, lim) in checks.items()}
     print(json.dumps(line))

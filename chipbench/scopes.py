@@ -29,33 +29,21 @@ A mapped program that runs an instruction its HLO text lacks gives its
 batch no split, so a map that no longer matches the programs makes the
 metrics read nothing rather than something wrong.
 
-The device's events can read up to about a millisecond early against the
-host's spans (on a v5e, programs were seen to start up to 0.9 ms before the
-host call that launched them).  The device's events are moved later by the
-least shift that puts every program after its launch (``device_lag``)
-before they are set against the host spans.
+The device's events are moved later by ``trace_reduce.device_lag`` before
+they are set against the host spans, as ``trace_reduce`` moves them.
 
-    python3 chipbench/scopes.py --workload <cell> --seed <n> --seconds <s>
-
-runs the benchmark's set-up and a traced window of the cell, as
-``run.py --trace 1`` does, and prints one JSON line: whether the run is
-correct, every per-layer metric of the cell and the seven that read this
-split (``METRICS``), ``output_tok_s`` of the traced window, and the split.
+``run.py --trace 1`` reduces its window's trace by ``reduce_scopes`` over
+the maps of ``program_maps``, compiled after the window, and its readers
+(``decode_*_ms``, ``prefill_attention_share``, ``engine_idle_ms``) read the
+split; ``summary`` goes on its result line.
 """
 from __future__ import annotations
 
 import bisect
-import pathlib
 import re
-import sys
 from collections import Counter, defaultdict
 
-ROOT = pathlib.Path(__file__).resolve().parents[1]
-for p in (ROOT, ROOT / "src"):
-    if str(p) not in sys.path:
-        sys.path.insert(0, str(p))
-
-from chipbench import trace_reduce  # noqa: E402
+from chipbench import trace_reduce
 
 SCOPES = ("attention", "kv_write", "mlp", "head")
 OTHER = "other"
@@ -63,18 +51,8 @@ HEAD = "head"
 PHASES = ("engine.prefill", "engine.first_token", "engine.decode",
           "engine.to_host")
 FIRST_TOKEN = "engine.first_token"
-EXECUTE = "PJRT_LoadedExecutable_Execute"    # the host's launch of a program
 OUTSIDE = "outside phases"
 TOP_UNSCOPED = 5
-
-# the per-layer metrics that read the split (chipbench/metrics/<name>.py)
-METRICS = [{"name": "decode_attention_ms", "unit": "ms"},
-           {"name": "decode_kv_write_ms", "unit": "ms"},
-           {"name": "decode_mlp_ms", "unit": "ms"},
-           {"name": "decode_head_ms", "unit": "ms"},
-           {"name": "decode_other_ms", "unit": "ms"},
-           {"name": "prefill_attention_share", "unit": "%"},
-           {"name": "engine_idle_ms", "unit": "ms"}]
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*)$")
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+) .*\{$")
@@ -192,12 +170,9 @@ def reduce_scopes(planes, maps: dict) -> list:
                      p.name).group(1)), default=None)
     if device is None:
         raise ValueError("no TPU device plane in the trace")
-    lines = {ln.name: trace_reduce._events(ln) for ln in device.lines}
+    lines, lag = trace_reduce.device_lines(device, spans)
     modules = lines.get(trace_reduce.MODULES_LINE, [])
-    lag = device_lag(spans, modules)
-    modules = [(n, a + lag, b + lag) for n, a, b in modules]
-    ops = sorted(((n, a + lag, b + lag) for n, a, b
-                  in lines.get(trace_reduce.OPS_LINE, [])), key=lambda e: e[1])
+    ops = sorted(lines.get(trace_reduce.OPS_LINE, []), key=lambda e: e[1])
     starts = [e[1] for e in ops]
     own = self_times(ops)
     out = []
@@ -212,18 +187,6 @@ def reduce_scopes(planes, maps: dict) -> list:
             split["device_lag_s"] = lag / 1e9
         out.append(split)
     return out
-
-
-def device_lag(spans, modules) -> float:
-    """How far, at least, the device's events read early against the
-    host's clock: no program starts before the host call that launched it
-    (``EXECUTE``), and the ``i``-th such call on the harness's thread
-    launches the ``i``-th program.  0 where the counts differ."""
-    launches = sorted(a for n, a, _ in spans if n.startswith(EXECUTE))
-    starts = sorted(a for _, a, _ in modules)
-    if len(launches) != len(starts):
-        return 0.0
-    return max([0.0] + [h - d for h, d in zip(launches, starts)])
 
 
 def _split(span, mine, phases, ops, starts, own, maps):
@@ -266,7 +229,7 @@ def decode_ms(rec: dict, scope: str):
     return 1e3 * secs / steps if secs > 0 else None
 
 
-# -- the traced run -------------------------------------------------------------------------
+# -- the maps, after the window -------------------------------------------------------------------------
 def program_maps(engine, records) -> dict:
     """``hlo_scopes`` of the prefill and decode-scan programs that each
     prompt length of the window ran, keyed as ``reduce_scopes`` wants.
@@ -312,36 +275,6 @@ def _program_maps(engine, records) -> dict:
     return maps
 
 
-def traced_window(system, batches, seconds, chips):
-    """``run.traced_window``, returning the trace's planes too."""
-    import glob
-    import os
-    import shutil
-    import tempfile
-
-    import jax
-    from jax.profiler import ProfileData
-
-    from chipbench import run
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    tmp = tempfile.mkdtemp(prefix="chipbench-trace-")
-    try:
-        with jax.profiler.trace(tmp, profiler_options=opts):
-            with jax.profiler.TraceAnnotation(trace_reduce.WINDOW):
-                records, served = run.run_window(
-                    system, batches, seconds,
-                    annotate=jax.profiler.TraceAnnotation)
-        files = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"),
-                          recursive=True)
-        if len(files) != 1:
-            raise ValueError(f"expected one trace under {tmp}, found {files}")
-        planes = list(ProfileData.from_file(files[0]).planes)
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    return records, served, planes
-
-
 def summary(records, programs, split) -> dict:
     """The split over the window: decode ms per step by scope beside the
     decode scans' own device time per step (``trace_reduce``), prefill
@@ -360,9 +293,7 @@ def summary(records, programs, split) -> dict:
         idle.update(s["idle"])
         unscoped.update(dict(s["unscoped"]))
     per_step = lambda c: {k: 1e3 * v / max(steps, 1) for k, v in c.items()}
-    lag = next((s["device_lag_s"] for s in split if s), 0.0)
-    return {"batches_split": n, "device_lag_ms": 1e3 * lag,
-            "decode_ms_per_step": per_step(decode),
+    return {"batches_split": n, "decode_ms_per_step": per_step(decode),
             "decode_scan_ms_per_step": 1e3 * scans / max(steps, 1),
             "prefill_s": dict(prefill),
             "idle_ms_per_batch": {k: 1e3 * v / max(n, 1)
@@ -370,63 +301,3 @@ def summary(records, programs, split) -> dict:
             "unscoped_decode_ms_per_step": [
                 [k, 1e3 * v / max(steps, 1)]
                 for k, v in unscoped.most_common(TOP_UNSCOPED)]}
-
-
-def main(argv=None) -> int:
-    import argparse
-    import json
-    import time
-
-    from chipbench import gate, generator, run, stats
-    p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    p.add_argument("--workload", required=True)
-    p.add_argument("--seed", type=int, required=True)
-    p.add_argument("--seconds", type=float, required=True)
-    args = p.parse_args(argv)
-    cell = run.load_cell(args.workload)
-    try:
-        device = gate.device_gate(cell.chips)
-    except gate.NoChip as e:
-        print(f"chipbench: {e}", file=sys.stderr)
-        return 2
-    run.enable_compile_cache()
-    compiles = gate.CompileCounter()
-    spec, weights, system = run.build(cell, args.seed)
-    batches = generator.Batches(cell.mix, cell.params["batch_tokens"],
-                                system.vocab, args.seed)
-    run.warm_up(system, batches)
-    setup_s = time.perf_counter() - run.T_PROCESS
-    before = compiles.compiles
-    records, served, planes = traced_window(system, batches, args.seconds,
-                                            cell.chips)
-    in_window = compiles.compiles - before
-    reduced = trace_reduce.reduce_planes(planes, cell.chips)
-    reduced["scopes"] = reduce_scopes(planes,
-                                      program_maps(system.engine, records))
-    rec = run.metric_record(cell, spec, system, records, reduced,
-                            device["kind"])
-    entries = cell.per_layer + METRICS
-    metrics = run.read_metrics(entries, rec)
-    device = dict(device, busy_s=reduced["busy_s"],
-                  window_s=reduced["window_s"])
-    m = run.Measured(spec=spec, weights=weights, records=records,
-                     served=served, metrics=metrics, device=device,
-                     setup_s=setup_s, compiles_in_window=in_window,
-                     per_layer_unread=[e["name"] for e in entries
-                                       if e["name"] not in metrics])
-    checks, _ = run.judge(cell, m, args.seed)
-    run.report_checks(checks)
-    print(json.dumps({
-        "correct": all(v <= lim for v, lim in checks.values()),
-        "metrics": metrics, "device": device,
-        "traced_output_tok_s": stats.output_tok_s(records),
-        "scopes": summary(records, reduced["batches"], reduced["scopes"]),
-        "breakdown": reduced["breakdown"],
-        "per_layer_unread": m.per_layer_unread,
-        "checks": {k: {"value": v, "limit": lim}
-                   for k, (v, lim) in checks.items()}}))
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

@@ -3,7 +3,9 @@
 A record is one ``ServingEngine.generate`` call of the window: its batch
 size, prompt and output lengths, when it was handed over and when it
 returned (seconds from the window's start, on the harness's clock), and
-the program's own ``prefill_s`` and ``decode_s`` for it.
+the program's own ``prefill_s`` and ``decode_s`` for it, and the counters
+the program reported for the call (``GenerationResult.counters``, where the
+program has them).
 """
 from __future__ import annotations
 
@@ -20,6 +22,7 @@ class BatchRecord:
     end_s: float          # generate returned
     prefill_s: float      # prefill and the first token, as the program times it
     decode_s: float       # the other output_len - 1 tokens
+    counters: dict = dataclasses.field(default_factory=dict)
 
 
 def output_tok_s(records: Sequence[BatchRecord]) -> float:

@@ -15,6 +15,7 @@ from __future__ import annotations
 import json
 import math
 import pathlib
+import sys
 import types
 
 import jax
@@ -22,10 +23,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import check, gate, generator, run, stats, trace_reduce
-from chipbench.counts import dense_gqa as counts
-from chipbench.programs import dense_gqa as prog
-from chipbench.reference import dense_gqa as ref
+from chipbench import (check, gate, generator, run, scopes, stats,
+                       trace_reduce)
+from chipbench.counts import dense_gqa as dense_counts
+from chipbench.programs import dense_gqa as dense_prog
+from chipbench.reference import dense_gqa as dense_ref
 from repro.models.transformer import Model
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -41,13 +43,18 @@ def conf(name: str) -> dict:
                        / f"{name}.json").read_text())
 
 
+def triple(c: dict):
+    """The configuration's own program, reference and counts modules."""
+    return (run.part("programs", c["program"]),
+            run.part("reference", c["reference"]),
+            run.part("counts", c["counts"]))
+
+
 def small(name: str, **sizes) -> dict:
-    """The configuration at a CPU test's size, every flag as published."""
+    """The configuration at a CPU test's size (its program's ``SMALL``),
+    every flag as published."""
     c = conf(name)
-    c["config"].update(dict(hidden_size=64, intermediate_size=160,
-                            num_attention_heads=4, num_key_value_heads=2,
-                            head_dim=16, num_hidden_layers=2,
-                            vocab_size=300), **sizes)
+    c["config"].update(triple(c)[0].SMALL, **sizes)
     return c
 
 
@@ -122,9 +129,9 @@ def test_program_span_arithmetic():
 
 # -- counts --------------------------------------------------------------------------
 def _tiny_spec(tied=False):
-    return ref.Spec(layers=2, d_model=4, n_heads=2, n_kv=1, d_head=2,
-                    d_ff=8, vocab=10, rows=16, theta=1e4, eps=1e-6,
-                    qkv_bias=False, qk_norm=False, tied=tied)
+    return dense_ref.Spec(layers=2, d_model=4, n_heads=2, n_kv=1, d_head=2,
+                          d_ff=8, vocab=10, rows=16, theta=1e4, eps=1e-6,
+                          qkv_bias=False, qk_norm=False, tied=tied)
 
 
 def test_counts_against_hand_worked_totals():
@@ -133,55 +140,73 @@ def test_counts_against_hand_worked_totals():
     per_tok = 64 + 32 + 192
     # prefill of 3 tokens, batch 2: causal pairs 6, attention 4*2*2*6=96
     want = 2 * (2 * (3 * per_tok + 96) + 2 * 4 * 10)
-    assert counts.prefill_flops(s, 2, 3) == want
+    assert dense_counts.prefill_flops(s, 2, 3) == want
     # decode steps writing positions 3 and 4: they attend to 4 and 5
     want = 2 * (2 * (2 * per_tok + 2 * 4 * 10) + 2 * (4 * 2 * 2) * (4 + 5))
-    assert counts.decode_flops(s, 2, 3, 2) == want
+    assert dense_counts.decode_flops(s, 2, 3, 2) == want
     # bytes: weights 1000 of which the table 128 (16 rows x 4 x 2 B);
     # gathered rows 2 x 4 x 2 B; KV per position 2 layers x 2 x 1 x 2 x 2 B
     w = 1000 - 128 + 16
     kv = 2 * 16 * ((3 + 4) + 2)
-    assert counts.decode_bytes(s, 2, 3, 2, 1000, 128) == 2 * w + kv
+    assert dense_counts.decode_bytes(s, 2, 3, 2, 1000, 128) == 2 * w + kv
     tied = _tiny_spec(tied=True)
-    assert counts.decode_bytes(tied, 2, 3, 2, 1000, 128) == 2 * 1000 + kv
+    assert dense_counts.decode_bytes(tied, 2, 3, 2, 1000, 128) == \
+        2 * 1000 + kv
 
 
 def test_step_counts_add_up():
     s = _tiny_spec()
-    total = counts.decode_flops(s, 3, 5, 4)
-    assert total == sum(counts.decode_flops(s, 3, p, 1) for p in range(5, 9))
-    nb = counts.decode_bytes(s, 3, 5, 4, 1000, 128)
-    assert nb == sum(counts.decode_bytes(s, 3, p, 1, 1000, 128)
+    total = dense_counts.decode_flops(s, 3, 5, 4)
+    assert total == sum(dense_counts.decode_flops(s, 3, p, 1)
+                        for p in range(5, 9))
+    nb = dense_counts.decode_bytes(s, 3, 5, 4, 1000, 128)
+    assert nb == sum(dense_counts.decode_bytes(s, 3, p, 1, 1000, 128)
                      for p in range(5, 9))
 
 
-@pytest.mark.parametrize("name", CONFIGS)
-def test_weight_bytes_match_param_count(name):
-    c = conf(name)
+# -- each configuration through its own modules -----------------------------------
+# Each check takes a configuration file's contents and reaches its family's
+# code only through the modules that the file names (``triple``), so that a
+# family that brings its own modules goes through the same checks.
+def _check_weight_bytes(c):
+    prog, ref, _ = triple(c)
     cfg = prog.model_config(c)
     spec = ref.spec_from_config(c, prog.embedding_rows(cfg))
     w = jax.eval_shape(lambda k: ref.init_weights(spec, k), jax.random.key(0))
-    norms = spec.d_model + (2 * spec.layers * spec.d_head if spec.qk_norm
-                            else 0)   # final norm and QK-norm: not counted
-    n = sum(x.size for x in jax.tree.leaves(w)) - norms
+    n = sum(x.size for x in jax.tree.leaves(w)) - ref.uncounted_params(spec)
     assert n == cfg.param_count()
     params = prog.program_params(Model(cfg), w)
-    assert sum(x.size for x in jax.tree.leaves(params)) == n + norms
+    assert sum(x.size for x in jax.tree.leaves(params)) == \
+        n + ref.uncounted_params(spec)
 
 
-# -- the reference against the program -------------------------------------------------
-def _f32_program(c):
+def _check_counts_add_up_by_step(c):
+    """What the metrics need of the counts: positive, and a call's decode
+    steps add up to the steps one at a time."""
+    prog, ref, counts = triple(c)
+    cfg = prog.model_config(c)
+    spec = ref.spec_from_config(c, prog.embedding_rows(cfg))
+    assert counts.prefill_flops(spec, 2, 16) > 0
+    total = counts.decode_flops(spec, 3, 5, 4)
+    assert total > 0 and total == pytest.approx(
+        sum(counts.decode_flops(spec, 3, p, 1) for p in range(5, 9)))
+    wb, eb = 10 ** 9, 10 ** 8
+    nb = counts.decode_bytes(spec, 3, 5, 4, wb, eb)
+    assert nb > 0 and nb == pytest.approx(
+        sum(counts.decode_bytes(spec, 3, p, 1, wb, eb) for p in range(5, 9)))
+
+
+def _f32_program(prog, c):
     cfg = prog.model_config(c).scaled(param_dtype=jnp.float32,
                                       compute_dtype=jnp.float32)
     return cfg, Model(cfg)
 
 
-@pytest.mark.parametrize("name", CONFIGS)
-def test_reference_matches_program_prefill_and_decode(name):
+def _check_reference_matches_program(c):
     """Prefill then decode through the cache, in float32, against the
     reference's teacher-forced logits at every position."""
-    c = small(name)
-    cfg, model = _f32_program(c)
+    prog, ref, _ = triple(c)
+    cfg, model = _f32_program(prog, c)
     spec = ref.spec_from_config(c, prog.embedding_rows(cfg))
     w = ref.init_weights(spec, jax.random.key(3), jnp.float32)
     params = prog.program_params(model, w)
@@ -198,23 +223,20 @@ def test_reference_matches_program_prefill_and_decode(name):
             got.append(logits[:, 0, :spec.vocab])
         got = np.stack(got, 1)
         for r in range(b):
-            h = ref._hidden(spec, w, jnp.asarray(toks[r]), jnp.float32)
-            head = (w["embed"].T if spec.tied else w["head"])[:, :spec.vocab]
-            want = np.asarray(h @ head)[ii - 1:]
+            want = np.asarray(ref.logits(spec, w, jnp.asarray(toks[r]),
+                                         jnp.float32))[ii - 1:]
             np.testing.assert_allclose(got[r], want, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.parametrize("name", CONFIGS)
-def test_gaps_are_zero_on_the_references_own_greedy_tokens(name):
-    c = small(name)
+def _check_gaps_zero_on_greedy_tokens(c):
+    prog, ref, _ = triple(c)
     cfg = prog.model_config(c)
     spec = ref.spec_from_config(c, prog.embedding_rows(cfg))
     w = ref.init_weights(spec, jax.random.key(4), jnp.float32)
     seq = np.arange(8, dtype=np.int32)       # the prompt
     for _ in range(4):                       # greedy from the reference itself
-        h = ref._hidden(spec, w, jnp.asarray(seq), jnp.float32)
-        head = (w["embed"].T if spec.tied else w["head"])[:, :spec.vocab]
-        seq = np.append(seq, int(np.argmax(h[-1] @ head))).astype(np.int32)
+        z = ref.logits(spec, w, jnp.asarray(seq), jnp.float32)
+        seq = np.append(seq, int(np.argmax(z[-1]))).astype(np.int32)
     served = seq[8:]
     best, at = ref.gaps(spec, w, jnp.asarray(seq[:-1]), jnp.asarray(served), 7)
     np.testing.assert_allclose(np.asarray(best) - np.asarray(at), 0, atol=1e-5)
@@ -223,13 +245,33 @@ def test_gaps_are_zero_on_the_references_own_greedy_tokens(name):
     assert (np.asarray(best) - np.asarray(at)).min() > 0
 
 
+@pytest.mark.parametrize("name", CONFIGS)
+def test_weight_bytes_match_param_count(name):
+    _check_weight_bytes(conf(name))
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_counts_add_up_by_step(name):
+    _check_counts_add_up_by_step(conf(name))
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_reference_matches_program_prefill_and_decode(name):
+    _check_reference_matches_program(small(name))
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_gaps_are_zero_on_the_references_own_greedy_tokens(name):
+    _check_gaps_zero_on_greedy_tokens(small(name))
+
+
 def test_quantize_rounds_per_output_channel():
     x = jnp.asarray([[1.0, -2.0], [0.5, 0.25]], jnp.float32)
-    q = np.asarray(ref._quantize(x, "int8", (0,)))
+    q = np.asarray(dense_ref._quantize(x, "int8", (0,)))
     # column scales 1/127 and 2/127: both columns keep their maxima
     assert q[0, 0] == 1.0 and q[0, 1] == -2.0
     assert abs(q[1, 1] - 0.25) <= 1 / 127
-    assert np.asarray(ref._quantize(x, "fp8", (0,)))[0, 1] == -2.0
+    assert np.asarray(dense_ref._quantize(x, "fp8", (0,)))[0, 1] == -2.0
 
 
 def test_sample_requests_holds_a_longest_prompt():
@@ -316,23 +358,91 @@ def test_trace_reduce_needs_the_window_and_a_device():
         trace_reduce.reduce_planes([host])
 
 
+MS = 1_000_000      # ns
+
+
+def _window_trace(prompt_lens, early=0.0):
+    """A window of one batch per prompt length, 10 ms each and 0.5 ms
+    apart.  In each: prefill (attention 2 ms, mlp 1 ms), an eager sample
+    (0.1 ms), then the decode scan, a while of 5 ms holding attention 2,
+    kv_write 0.5, mlp 1.5 and head 0.5 ms; every program starts at its
+    host launch.  ``early``: how far the device's clock reads early."""
+    host, modules, ops = [], [], []
+    t = 1 * MS
+    for k, ii in enumerate(prompt_lens):
+        host.append(_ev(f"{trace_reduce.BATCH_PREFIX}{ii}", t, 10 * MS))
+        for j, (name, start, dur, inner) in enumerate((
+                ("jit__lambda", 0.1, 3.0, (("fusion.1", 2.0),
+                                           ("fusion.2", 1.0))),
+                ("jit_argmax", 3.3, 0.1, (("argmax.1", 0.1),)),
+                ("jit__decode_scan", 3.5, 5.0, (
+                    ("fusion.7", 2.0), ("fusion.8", 0.5), ("fusion.9", 1.5),
+                    ("fusion.10", 0.5))))):
+            a = t + start * MS
+            host.append(_ev(f"{trace_reduce.EXECUTE} {name}", a, 1000))
+            modules.append(_ev(f"{name}({3 * k + j})", a - early, dur * MS))
+            if name == "jit__decode_scan":
+                ops.append(_ev("%while.1 = while()", a - early, dur * MS))
+            for op, d in inner:
+                ops.append(_ev(f"%{op} = {op.split('.')[0]}()", a - early,
+                               d * MS))
+                a += d * MS
+        t += 10.5 * MS
+    host.append(_ev(trace_reduce.WINDOW, 0, t))
+    return [_plane("/host:CPU", {"main": host}),
+            _plane("/device:TPU:0", {"XLA Modules": modules,
+                                     "XLA Ops": ops})]
+
+
+def test_programs_stay_in_their_batch_when_the_device_reads_early():
+    """The device's clock reads 0.9 ms early against the host's: every
+    program is moved after its launch, so each batch keeps its own."""
+    want = [{"decode_s": 5e-3, "prefill_s": 3.1e-3,
+             "decode_program": f"jit__decode_scan({3 * k + 2})"}
+            for k in range(3)]
+    r = trace_reduce.reduce_planes(_window_trace((8, 16, 8), early=0.9 * MS))
+    assert r["device_lag_s"] == pytest.approx(0.9e-3)
+    assert r["batches"] == [{**b, "decode_s": pytest.approx(b["decode_s"]),
+                             "prefill_s": pytest.approx(b["prefill_s"])}
+                            for b in want]
+    same = trace_reduce.reduce_planes(_window_trace((8, 16, 8)))
+    assert same["device_lag_s"] == 0
+    assert same["busy_s"] == pytest.approx(r["busy_s"])
+    assert [t for _, t in same["breakdown"]["idle_gaps"]] == pytest.approx(
+        [t for _, t in r["breakdown"]["idle_gaps"]])
+
+
 # -- the cells' files ---------------------------------------------------------------------
-@pytest.mark.parametrize("cell", CELLS)
-def test_every_cell_resolves_to_its_files(cell):
-    c = run.load_cell(cell)
+# what each of a configuration's three modules provides (``run.py``)
+INTERFACE = {
+    "programs": ("model_config", "embedding_rows", "program_params",
+                 "embed_bytes", "SMALL"),
+    "reference": ("Spec", "spec_from_config", "init_weights", "gaps",
+                  "control_gaps", "logits", "uncounted_params"),
+    "counts": ("prefill_flops", "decode_flops", "decode_bytes"),
+}
+
+
+def _check_cell_files(c: run.Cell):
     assert c.chips == 1
     assert c.params["batch_tokens"] >= c.mix.longest
     assert c.params["check_requests"] >= 1
     assert c.params["limits"]["widest_gap"] > 0
     for key, kind in (("program", "programs"), ("reference", "reference"),
                       ("counts", "counts")):
-        run.part(kind, c.conf[key])
+        mod = run.part(kind, c.conf[key])
+        assert [n for n in INTERFACE[kind] if not hasattr(mod, n)] == []
     assert {m["name"] for m in c.end_to_end} >= {"output_tok_s", "setup_s"}
     assert c.per_layer
     for m in c.per_layer:
         assert callable(run.part("metrics", m["name"]).read)
     for m in c.end_to_end:
         assert callable(run.part("metrics", m["name"]).read)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_resolves_to_its_files(cell):
+    _check_cell_files(run.load_cell(cell))
 
 
 def test_config_files_agree_with_benchmark_json():
@@ -347,23 +457,25 @@ def test_config_files_agree_with_benchmark_json():
 def test_metric_readers_on_a_fabricated_record():
     s = _tiny_spec()
     recs = _records()
-    rec = {"batches": recs, "spec": s, "counts": counts,
+    rec = {"batches": recs, "spec": s, "counts": dense_counts,
            "programs": [{"prefill_s": 0.05, "decode_s": 0.5},
                         {"prefill_s": 0.2, "decode_s": 0.9}],
            "peaks": {"flops_per_s": 1e6, "hbm_bytes_per_s": 1e6},
            "trace": {"busy_s": 0.75, "window_s": 1.0},
            "weight_bytes": 1000, "embed_bytes": 128}
-    flops = sum(counts.decode_flops(s, 4, r.prompt_len, 9) for r in recs)
+    flops = sum(dense_counts.decode_flops(s, 4, r.prompt_len, 9)
+                for r in recs)
     assert run.part("metrics", "decode_mfu").read(rec) == \
         pytest.approx(100 * flops / (1.4 * 1e6))
-    pf = counts.prefill_flops(s, 4, 8) + counts.prefill_flops(s, 4, 16)
+    pf = (dense_counts.prefill_flops(s, 4, 8)
+          + dense_counts.prefill_flops(s, 4, 16))
     assert run.part("metrics", "prefill_mfu").read(rec) == \
         pytest.approx(100 * pf / (0.25 * 1e6))
     assert run.part("metrics", "device_idle_share").read(rec) == \
         pytest.approx(25.0)
     roof = run.part("metrics", "decode_roofline").read(rec)
-    least = sum(max(counts.decode_flops(s, 4, p, 1),
-                    counts.decode_bytes(s, 4, p, 1, 1000, 128)) / 1e6
+    least = sum(max(dense_counts.decode_flops(s, 4, p, 1),
+                    dense_counts.decode_bytes(s, 4, p, 1, 1000, 128)) / 1e6
                 for r in recs for p in range(r.prompt_len, r.prompt_len + 9))
     assert roof == pytest.approx(100 * least / 1.4)
     rec["programs"] = [None, None]
@@ -373,12 +485,14 @@ def test_metric_readers_on_a_fabricated_record():
 
 # -- whole runs at a tiny size, on the CPU -----------------------------------------------
 def _tiny_root(tmp_path, name="qwen3-0.6b", limit=0.05, sizes=None,
-               mix=None):
+               mix=None, c=None):
+    """A checkout with one cell, ``tiny.t``: the configuration ``name`` at
+    the CPU tests' size (or the configuration file ``c``)."""
     root = tmp_path / "checkout"
     for d in ("configs", "cells", "traffic"):
         (root / "chipbench" / d).mkdir(parents=True)
     (root / "chipbench/configs/tiny.json").write_text(
-        json.dumps(small(name, **(sizes or {}))))
+        json.dumps(c or small(name, **(sizes or {}))))
     (root / "chipbench/traffic/t.json").write_text(json.dumps(mix or {
         "buckets": [{"prompt_len": 8, "weight": 1},
                     {"prompt_len": 16, "weight": 1}], "output_len": 6}))
@@ -414,9 +528,8 @@ def cpu_run(monkeypatch):
     return go
 
 
-@pytest.mark.parametrize("name", CONFIGS)
-def test_tiny_run_is_correct(name, tmp_path, capsys, cpu_run):
-    line, err = cpu_run(_tiny_root(tmp_path, name), capsys)
+def _check_tiny_run(root, capsys, cpu_run):
+    line, err = cpu_run(root, capsys)
     assert line["correct"] is True, line["checks"]
     assert list(line)[:5] == ["correct", "attempted", "failed", "metrics",
                               "device"]
@@ -426,6 +539,103 @@ def test_tiny_run_is_correct(name, tmp_path, capsys, cpu_run):
     assert line["setup"]["batches"] % 2 == 0        # whole rounds of the mix
     assert line["checks"]["compiles_in_window"]["value"] == 0
     assert err.strip().splitlines()[-1].startswith("check span_excess_s")
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_tiny_run_is_correct(name, tmp_path, capsys, cpu_run):
+    _check_tiny_run(_tiny_root(tmp_path, name), capsys, cpu_run)
+
+
+def _split_maps(prompt_lens):
+    """The HLO scope maps of ``_window_trace``'s programs."""
+    maps = {}
+    for ii in set(prompt_lens):
+        span = f"{trace_reduce.BATCH_PREFIX}{ii}"
+        maps[(span, "jit__lambda")] = {"fusion.1": "attention",
+                                       "fusion.2": "mlp"}
+        maps[(span, "jit__decode_scan")] = {
+            "while.1": "other", "fusion.7": "attention",
+            "fusion.8": "kv_write", "fusion.9": "mlp", "fusion.10": "head"}
+    return maps
+
+
+def test_tiny_traced_run_reads_every_per_layer_metric(tmp_path, capsys,
+                                                      cpu_run, monkeypatch):
+    """``run.main --trace 1``: the window runs under the profiler, and its
+    trace (here a fabricated TPU trace of the window's own batches, read
+    0.9 ms early) is reduced, split by scope over the maps compiled after
+    the window, and read by every per-layer metric of the cell."""
+    monkeypatch.setattr(gate, "device_gate", lambda chips: {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": chips})
+    seen = {}
+    real = run.run_window
+
+    def run_window(*args, **kw):
+        seen["records"], served = real(*args, **kw)
+        return seen["records"], served
+    lens = lambda: [r.prompt_len for r in seen["records"]]
+    monkeypatch.setattr(run, "run_window", run_window)
+    monkeypatch.setattr(trace_reduce, "load_planes", lambda path:
+                        _window_trace(lens(), early=0.9 * MS))
+    monkeypatch.setattr(scopes, "program_maps",
+                        lambda engine, records: _split_maps(lens()))
+    line, _ = cpu_run(_tiny_root(tmp_path), capsys, extra=("--trace", "1"))
+    assert line["correct"] is True, line["checks"]
+    assert line["setup"]["per_layer_unread"] == []
+    assert set(line["metrics"]) == {m["name"] for m in BENCH["per_layer"]}
+    got = {k: v["value"] for k, v in line["metrics"].items()}
+    steps = 5                                   # output_len 6
+    want = {"decode_attention_ms": 2.0, "decode_kv_write_ms": 0.5,
+            "decode_mlp_ms": 1.5, "decode_head_ms": 0.5,
+            "decode_other_ms": 0.5}
+    for k, v in want.items():
+        assert got[k] == pytest.approx(v / steps)
+    assert got["prefill_attention_share"] == pytest.approx(100 * 2 / 3.1)
+    assert got["engine_idle_ms"] == pytest.approx(10 - 3 - 0.1 - 5)
+    assert line["setup"]["device_lag_s"] == pytest.approx(0.9e-3)
+    split = line["setup"]["split"]
+    assert split["batches_split"] == len(seen["records"])
+    assert sum(split["decode_ms_per_step"].values()) == pytest.approx(
+        split["decode_scan_ms_per_step"])
+    assert line["device"]["window_s"] > line["device"]["busy_s"] > 0
+
+
+class _Engine:
+    """Returns empty tokens and, with ``counters``, the call's number."""
+
+    def __init__(self, counters=True):
+        self.calls, self.counters = 0, counters
+
+    def generate(self, prompts, n):
+        self.calls += 1
+        res = types.SimpleNamespace(
+            tokens=np.zeros((prompts.shape[0], n), np.int32),
+            prefill_s=0.0, decode_s=0.0)
+        if self.counters:
+            res.counters = {"call": self.calls, "rows": prompts.shape[0]}
+        return res
+
+
+def test_counters_reach_the_readers_on_their_batch(monkeypatch):
+    """What ``generate`` reports in its result's ``counters`` is on that
+    call's batch record, where a per-layer reader finds it."""
+    mix = generator.Mix(prompt_lens=(8, 16), weights=(1, 1), output_len=4)
+    batches = generator.Batches(mix, 32, 50, BIG_SEED)
+    system = run.System(engine=_Engine(), vocab=50, weight_bytes=0,
+                        embed_bytes=0)
+    records, _ = run.run_window(system, batches, 0.0)
+    assert [r.counters for r in records] == [
+        {"call": i + 1, "rows": r.batch} for i, r in enumerate(records)]
+    reader = types.ModuleType("chipbench.metrics.second_batch_rows")
+    reader.read = lambda rec: rec["batches"][1].counters["rows"]
+    monkeypatch.setitem(sys.modules, reader.__name__, reader)
+    got = run.read_metrics([{"name": "second_batch_rows", "unit": "1"}],
+                           {"batches": records})
+    assert got == {"second_batch_rows": {"value": records[1].batch,
+                                         "unit": "1"}}
+    system.engine = _Engine(counters=False)      # a result without them
+    records, _ = run.run_window(system, batches, 0.0)
+    assert all(r.counters == {} for r in records)
 
 
 def _altered_token(monkeypatch):
@@ -562,12 +772,11 @@ def _used(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_largest_programs_fit_one_v5e(cell, one_chip):
+def _check_largest_programs(c: run.Cell, one_chip):
     """The cell's largest prefill, its decode scan and its reference, as
     the run compiles them, each fit beside the weights on one chip."""
     from repro.inference.engine import ServingEngine
-    c = run.load_cell(cell)
+    prog, ref, _ = triple(c.conf)
     cfg = prog.model_config(c.conf)
     spec = ref.spec_from_config(c.conf, prog.embedding_rows(cfg))
     sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
@@ -599,3 +808,71 @@ def test_largest_programs_fit_one_v5e(cell, one_chip):
                            ("reference", check_)):
         assert _used(compiled) < HBM_BYTES, (name, _used(compiled))
     assert math.isfinite(_used(pre))
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_largest_programs_fit_one_v5e(cell, one_chip):
+    _check_largest_programs(run.load_cell(cell), one_chip)
+
+
+# -- a second family, through new modules alone -----------------------------------------
+SECOND = "second_family"
+
+
+class _Family(types.ModuleType):
+    """A family's module under a name of its own: the names ``INTERFACE``
+    lists for its kind and no others, taken from ``source``, with a record
+    of those read."""
+
+    def __init__(self, name, source, names):
+        super().__init__(name)
+        self._source, self._names, self.used = source, names, set()
+
+    def __getattr__(self, key):
+        if key not in self._names:
+            raise AttributeError(f"{self.__name__} has no {key!r}")
+        self.used.add(key)
+        return getattr(self._source, key)
+
+
+@pytest.fixture
+def second_family(monkeypatch):
+    """A configuration whose program, reference and counts are the modules
+    ``chipbench.<kind>.second_family``, found only by name: dense GQA
+    behind the interface alone."""
+    mods = {}
+    for kind, source in (("programs", dense_prog), ("reference", dense_ref),
+                         ("counts", dense_counts)):
+        mods[kind] = _Family(f"chipbench.{kind}.{SECOND}", source,
+                             INTERFACE[kind])
+        monkeypatch.setitem(sys.modules, mods[kind].__name__, mods[kind])
+    c = small("qwen3-0.6b")
+    c.update(program=SECOND, reference=SECOND, counts=SECOND)
+    return c, mods
+
+
+@pytest.mark.parametrize("check_", [
+    "weight_bytes", "counts", "reference", "gaps", "cell_files",
+    "largest_programs", "tiny_run"])
+def test_second_family_goes_through_by_module_names(
+        check_, second_family, tmp_path, capsys, cpu_run, request):
+    """Every check that is run per configuration or per cell, and a whole
+    run, take a configuration whose modules have other names, through new
+    files and entries alone."""
+    c, mods = second_family
+    root = _tiny_root(tmp_path, c=c)
+    cell = lambda: run.load_cell("tiny.t", root)
+    checks = {
+        "weight_bytes": lambda: _check_weight_bytes(c),
+        "counts": lambda: _check_counts_add_up_by_step(c),
+        "reference": lambda: _check_reference_matches_program(c),
+        "gaps": lambda: _check_gaps_zero_on_greedy_tokens(c),
+        "cell_files": lambda: _check_cell_files(cell()),
+        "largest_programs": lambda: _check_largest_programs(
+            cell(), request.getfixturevalue("one_chip")),
+        "tiny_run": lambda: _check_tiny_run(root, capsys, cpu_run),
+    }
+    checks[check_]()
+    assert mods["programs"].used and mods["reference"].used
+    if check_ in ("counts", "cell_files"):
+        assert mods["counts"].used

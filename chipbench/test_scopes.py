@@ -26,6 +26,10 @@ from repro.models.transformer import Model
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+# the per-layer metrics that read the split
+SPLIT = ("decode_attention_ms", "decode_kv_write_ms", "decode_mlp_ms",
+         "decode_head_ms", "decode_other_ms", "prefill_attention_share",
+         "engine_idle_ms")
 
 
 def test_names_are_the_programs():
@@ -98,7 +102,7 @@ def _trace(engine_spans=True, early=0, launches=False):
     host = [_ev(trace_reduce.WINDOW, 0, 1000),
             _ev("generate ii=8", 90, 600)]
     if launches:
-        host += [_ev(f"{scopes.EXECUTE} linkage", t, 1)
+        host += [_ev(f"{trace_reduce.EXECUTE} linkage", t, 1)
                  for t in (100, 250, 300)]
     if engine_spans:
         host += [_ev("engine.prefill", 95, 10, call=1),
@@ -195,21 +199,22 @@ def test_new_readers_on_the_fabricated_trace():
             "decode_other_ms": 130e-6 / 10,
             "prefill_attention_share": 100 * 60 / 110,
             "engine_idle_ms": 190e-6}
-    got = run.read_metrics(scopes.METRICS, rec)
+    entries = [m for m in BENCH["per_layer"] if m["name"] in SPLIT]
+    assert [m["name"] for m in entries] == list(want) == list(SPLIT)
+    got = run.read_metrics(entries, rec)
     assert {k: v["value"] for k, v in got.items()} == pytest.approx(want)
-    assert [m["name"] for m in scopes.METRICS] == list(want)
     # the five decode scopes add up to the decode scan's time per step
     decode = sum(want[m] for m in want if m.startswith("decode_"))
     assert decode == pytest.approx(
         1e3 * rec["programs"][0]["decode_s"] / 10)
-    assert run.read_metrics(scopes.METRICS, _record(None)) == {}
-    assert run.read_metrics(scopes.METRICS, _record([None])) == {}
+    assert run.read_metrics(entries, _record(None)) == {}
+    assert run.read_metrics(entries, _record([None])) == {}
 
 
 def test_existing_readings_unchanged_by_the_split():
     """The engine's spans move none of trace_reduce's numbers (only the
-    labels of idle gaps), and every accepted per-layer metric reads the
-    same with the split beside it."""
+    labels of idle gaps), and every per-layer metric that does not read
+    the split reads the same with the split beside it."""
     plain = trace_reduce.reduce_planes(_trace(engine_spans=False), chips=1)
     spanned = trace_reduce.reduce_planes(_trace(), chips=1)
     for key in ("busy_s", "window_s", "batches"):
@@ -227,7 +232,7 @@ def test_existing_readings_unchanged_by_the_split():
     with_split = _record(scopes.reduce_scopes(_trace(), MAPS))
     for rec in (without, with_split):
         rec.update(spec=spec, counts=counts)
-    entries = BENCH["per_layer"]
+    entries = [m for m in BENCH["per_layer"] if m["name"] not in SPLIT]
     before = run.read_metrics(entries, without)
     assert set(before) == {m["name"] for m in entries}
     assert run.read_metrics(entries, with_split) == before

@@ -17,6 +17,13 @@ host thread.  On each TPU device plane:
     the longest idle gaps, each labelled by what the host was doing: the
     shortest host span on the harness's thread that covers the gap's
     middle, under the ``generate`` span that holds it, if any.
+
+The device's events can read up to about a millisecond early against the
+host's spans (on a v5e, programs were seen to start up to 0.99 ms before
+the host call that launched them), which would put a batch's programs into
+the span before it.  So each device plane's events are moved later by the
+least shift that puts every program after its launch (``device_lag``)
+before they are set against the host spans.
 """
 from __future__ import annotations
 
@@ -30,6 +37,7 @@ BATCH_PREFIX = "generate ii="
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+EXECUTE = "PJRT_LoadedExecutable_Execute"    # the host's launch of a program
 TOP = 10
 
 
@@ -64,6 +72,27 @@ def _host_spans(planes):
     raise ValueError(f"no host span {WINDOW!r} in the trace")
 
 
+def device_lag(spans, modules) -> float:
+    """How far, at least, the device's events read early against the
+    host's clock: no program starts before the host call that launched it
+    (``EXECUTE``), and the ``i``-th such call on the harness's thread
+    launches the ``i``-th program.  0 where the counts differ."""
+    launches = sorted(a for n, a, _ in spans if n.startswith(EXECUTE))
+    starts = sorted(a for _, a, _ in modules)
+    if len(launches) != len(starts):
+        return 0.0
+    return max([0.0] + [h - d for h, d in zip(launches, starts)])
+
+
+def device_lines(plane, spans):
+    """(events by line name, shift): a device plane's events moved later
+    by its ``device_lag`` against the host ``spans``."""
+    lines = {ln.name: _events(ln) for ln in plane.lines}
+    lag = device_lag(spans, lines.get(MODULES_LINE, []))
+    return {name: [(n, a + lag, b + lag) for n, a, b in evs]
+            for name, evs in lines.items()}, lag
+
+
 def _label(spans, t):
     """What the host was doing at time ``t``: the shortest covering span,
     under its ``generate`` span."""
@@ -88,15 +117,16 @@ def reduce_planes(planes, chips: int = 1) -> dict:
     if not devices:
         raise ValueError("no TPU device plane in the trace")
     busy_total, op_time = 0.0, defaultdict(float)
-    batches, gaps = None, []
+    batches, gaps, lag0 = None, [], 0.0
     for k, (_, plane) in enumerate(devices):
-        lines = {ln.name: _events(ln) for ln in plane.lines}
+        lines, lag = device_lines(plane, spans)
         ops = [e for e in lines.get(OPS_LINE, []) if e[2] > w0 and e[1] < w1]
         busy = _union([(a, b) for _, a, b in ops], w0, w1)
         busy_total += sum(b - a for a, b in busy)
         for name, a, b in ops:
             op_time[name] += min(b, w1) - max(a, w0)
         if k == 0:
+            lag0 = lag
             batches = _batch_programs(batch_spans, lines.get(MODULES_LINE, []))
             edges = [w0] + [x for iv in busy for x in iv] + [w1]
             gaps = [(edges[i], edges[i + 1])
@@ -107,6 +137,7 @@ def reduce_planes(planes, chips: int = 1) -> dict:
         "busy_s": busy_total / len(devices) / 1e9,
         "window_s": (w1 - w0) / 1e9,
         "batches": batches,
+        "device_lag_s": lag0 / 1e9,
         "breakdown": {
             "device_ops": [[n, t / len(devices) / 1e9] for n, t in
                            sorted(op_time.items(), key=lambda x: -x[1])[:TOP]],
@@ -134,11 +165,11 @@ def _batch_programs(batch_spans, modules):
     return out
 
 
-def reduce_dir(path: str, chips: int = 1) -> dict:
-    """Reduce the one ``.xplane.pb`` that a profiler session wrote under
-    ``path``."""
+def load_planes(path: str) -> list:
+    """The planes of the one ``.xplane.pb`` that a profiler session wrote
+    under ``path``."""
     from jax.profiler import ProfileData
     files = glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True)
     if len(files) != 1:
         raise ValueError(f"expected one trace under {path}, found {files}")
-    return reduce_planes(ProfileData.from_file(files[0]).planes, chips)
+    return list(ProfileData.from_file(files[0]).planes)
