@@ -11,9 +11,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.models import layers
 from repro.models.config import ModelConfig
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+
+# the layer scopes that the dense programs carry (``run.py``)
+SCOPES = (layers.ATTENTION, layers.KV_WRITE, layers.MLP, layers.HEAD)
 
 # the CPU tests' size: every flag of the configuration as published
 SMALL = dict(hidden_size=64, intermediate_size=160, num_attention_heads=4,

@@ -38,6 +38,9 @@ modules under those names, which provide:
     over the reference's weights ``w``, raising where the layouts differ;
     ``embed_bytes(params) -> int``, the embedding table's bytes;
     ``SMALL``, the configuration keys and values of the CPU tests' size;
+    ``SCOPES``, the layer scopes its compiled programs carry, each a name
+    that ``repro.models.layers.SCOPES`` declares; a traced run splits the
+    device time by these (``chipbench/scopes.py``);
   ``chipbench/reference/<name>.py``, the plain reference:
     ``Spec``, a hashable dataclass with at least ``vocab``;
     ``spec_from_config(conf, rows) -> Spec``;
@@ -292,8 +295,9 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
     unread, reduced = [], None
     if trace:
         reduced = trace_reduce.reduce_planes(planes, cell.chips)
+        names = part("programs", cell.conf["program"]).SCOPES
         reduced["scopes"] = scopes.reduce_scopes(
-            planes, scopes.program_maps(system.engine, records))
+            planes, scopes.program_maps(system.engine, records, names))
         metrics = read_metrics(cell.per_layer, metric_record(
             cell, spec, system, records, reduced, device["kind"]))
         unread = [m["name"] for m in cell.per_layer

@@ -2,14 +2,19 @@
 time inside ``generate`` by engine phase, from a profiler trace.
 
 The program names its layers inside its compiled programs with
-``jax.named_scope`` (``SCOPES``, as ``repro.models.layers.SCOPES`` has
-them) and the phases of ``ServingEngine.generate`` with host spans
-(``PHASES``, as ``repro.inference.engine.PHASES`` has them).  A TPU trace
-names each device operation by its HLO instruction only, so the scope of
-an operation is read from the compiled program's HLO text: the innermost
+``jax.named_scope`` (the names ``repro.models.layers.SCOPES`` declares) and
+the phases of ``ServingEngine.generate`` with host spans (``PHASES``, as
+``repro.inference.engine.PHASES`` has them).  Which scope names to read is
+the model family's own: its program module's ``SCOPES``
+(``chipbench/programs/<name>.py``), which ``run.py`` hands to
+``program_maps``; this module keeps no list of its own.  A TPU trace names
+each device operation by its HLO instruction only, so the scope of an
+operation is read from the compiled program's HLO text: the innermost
 listed scope in the instruction's ``op_name``, or in the ``op_name`` of the
 operations inside a fusion that carries none; an instruction under no
-listed scope is ``other``.
+listed scope is ``other``.  So a family that nests a scope of its own
+inside ``mlp`` (an expert layer), and lists it, has that scope's time
+apart from the rest of the block.
 
 For each ``generate`` span of the window (``trace_reduce.BATCH_PREFIX``):
 
@@ -45,7 +50,6 @@ from collections import Counter, defaultdict
 
 from chipbench import trace_reduce
 
-SCOPES = ("attention", "kv_write", "mlp", "head")
 OTHER = "other"
 HEAD = "head"
 PHASES = ("engine.prefill", "engine.first_token", "engine.decode",
@@ -61,10 +65,11 @@ _CALLS = re.compile(r"\bfusion\(.*\bcalls=%([\w.\-]+)")
 
 
 # -- HLO text -------------------------------------------------------------------
-def scope_of(op_name: str) -> str:
-    """The innermost listed scope on an ``op_name`` path, else ``other``."""
+def scope_of(op_name: str, names) -> str:
+    """The innermost of the scope ``names`` on an ``op_name`` path, else
+    ``other``."""
     for part in reversed(op_name.split("/")):
-        if part in SCOPES:
+        if part in names:
             return part
     return OTHER
 
@@ -73,11 +78,11 @@ def module_name(hlo_text: str) -> str:
     return hlo_text.split(None, 2)[1].rstrip(",")
 
 
-def hlo_scopes(hlo_text: str) -> dict:
-    """Instruction name -> scope for every instruction of a compiled
-    program's HLO text (``Compiled.as_text()``).  A fusion with no
-    ``op_name`` takes the one scope of the operations inside it that have
-    one, else ``other``."""
+def hlo_scopes(hlo_text: str, names) -> dict:
+    """Instruction name -> scope, one of ``names`` or ``other``, for every
+    instruction of a compiled program's HLO text (``Compiled.as_text()``).
+    A fusion with no ``op_name`` takes the one scope of the operations
+    inside it that have one, else ``other``."""
     own, calls, inner = {}, {}, defaultdict(set)
     computation = None
     for line in hlo_text.splitlines():
@@ -90,7 +95,7 @@ def hlo_scopes(hlo_text: str) -> dict:
             continue
         name, rest = m.groups()
         op = _OP_NAME.search(rest)
-        own[name] = scope_of(op.group(1)) if op else None
+        own[name] = scope_of(op.group(1), names) if op else None
         if op:
             inner[computation].add(own[name])
         c = _CALLS.search(rest)
@@ -230,9 +235,10 @@ def decode_ms(rec: dict, scope: str):
 
 
 # -- the maps, after the window -------------------------------------------------------------------------
-def program_maps(engine, records) -> dict:
-    """``hlo_scopes`` of the prefill and decode-scan programs that each
-    prompt length of the window ran, keyed as ``reduce_scopes`` wants.
+def program_maps(engine, records, names) -> dict:
+    """``hlo_scopes`` over the scope ``names`` of the prefill and
+    decode-scan programs that each prompt length of the window ran, keyed
+    as ``reduce_scopes`` wants.
 
     Compiles them afresh: JAX's in-memory caches are cleared, and the
     persistent cache's key takes in the metadata.  The key leaves
@@ -245,13 +251,13 @@ def program_maps(engine, records) -> dict:
     key = jax.config.jax_compilation_cache_include_metadata_in_key
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     try:
-        return _program_maps(engine, records)
+        return _program_maps(engine, records, names)
     finally:
         jax.config.update("jax_compilation_cache_include_metadata_in_key",
                           key)
 
 
-def _program_maps(engine, records) -> dict:
+def _program_maps(engine, records, names) -> dict:
     import jax
     import jax.numpy as jnp
     maps = {}
@@ -271,7 +277,7 @@ def _program_maps(engine, records) -> dict:
                                      oo - 1).compile().as_text()
         for text in (pre, dec):
             maps[(f"{trace_reduce.BATCH_PREFIX}{ii}", module_name(text))] = \
-                hlo_scopes(text)
+                hlo_scopes(text, names)
     return maps
 
 

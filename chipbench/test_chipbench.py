@@ -361,12 +361,24 @@ def test_trace_reduce_needs_the_window_and_a_device():
 MS = 1_000_000      # ns
 
 
-def _window_trace(prompt_lens, early=0.0):
+# ms in each decode step's fabricated fusion, by the scope it is under
+SCAN_MS = {"attention": 2.0, "kv_write": 0.5, "mlp": 1.5, "head": 0.5}
+
+
+def _scan(names):
+    """The decode scan's fusions: ``fusion.7`` on, one under each of the
+    scope ``names`` in order, 0.5 ms where ``SCAN_MS`` has no time."""
+    return tuple((f"fusion.{7 + i}", SCAN_MS.get(n, 0.5))
+                 for i, n in enumerate(names))
+
+
+def _window_trace(prompt_lens, early=0.0, scan=_scan(dense_prog.SCOPES)):
     """A window of one batch per prompt length, 10 ms each and 0.5 ms
     apart.  In each: prefill (attention 2 ms, mlp 1 ms), an eager sample
-    (0.1 ms), then the decode scan, a while of 5 ms holding attention 2,
-    kv_write 0.5, mlp 1.5 and head 0.5 ms; every program starts at its
-    host launch.  ``early``: how far the device's clock reads early."""
+    (0.1 ms), then the decode scan, a while 0.5 ms longer than the fusions
+    of ``scan`` it holds: for dense GQA 5 ms, holding attention 2, kv_write
+    0.5, mlp 1.5 and head 0.5 ms; every program starts at its host launch.
+    ``early``: how far the device's clock reads early."""
     host, modules, ops = [], [], []
     t = 1 * MS
     for k, ii in enumerate(prompt_lens):
@@ -375,9 +387,8 @@ def _window_trace(prompt_lens, early=0.0):
                 ("jit__lambda", 0.1, 3.0, (("fusion.1", 2.0),
                                            ("fusion.2", 1.0))),
                 ("jit_argmax", 3.3, 0.1, (("argmax.1", 0.1),)),
-                ("jit__decode_scan", 3.5, 5.0, (
-                    ("fusion.7", 2.0), ("fusion.8", 0.5), ("fusion.9", 1.5),
-                    ("fusion.10", 0.5))))):
+                ("jit__decode_scan", 3.5, 0.5 + sum(d for _, d in scan),
+                 scan))):
             a = t + start * MS
             host.append(_ev(f"{trace_reduce.EXECUTE} {name}", a, 1000))
             modules.append(_ev(f"{name}({3 * k + j})", a - early, dur * MS))
@@ -416,7 +427,7 @@ def test_programs_stay_in_their_batch_when_the_device_reads_early():
 # what each of a configuration's three modules provides (``run.py``)
 INTERFACE = {
     "programs": ("model_config", "embedding_rows", "program_params",
-                 "embed_bytes", "SMALL"),
+                 "embed_bytes", "SMALL", "SCOPES"),
     "reference": ("Spec", "spec_from_config", "init_weights", "gaps",
                   "control_gaps", "logits", "uncounted_params"),
     "counts": ("prefill_flops", "decode_flops", "decode_bytes"),
@@ -484,10 +495,19 @@ def test_metric_readers_on_a_fabricated_record():
 
 
 # -- whole runs at a tiny size, on the CPU -----------------------------------------------
+def listed(bench, name):
+    """The per-layer entries of ``bench`` that list a cell of the
+    configuration ``name``: those its cells report and are checked on."""
+    cells = {w["name"] for w in bench["workloads"] if w["config"] == name}
+    return [m for m in bench["per_layer"]
+            if cells & set(m.get("workloads", cells))]
+
+
 def _tiny_root(tmp_path, name="qwen3-0.6b", limit=0.05, sizes=None,
-               mix=None, c=None):
+               mix=None, c=None, bench=BENCH):
     """A checkout with one cell, ``tiny.t``: the configuration ``name`` at
-    the CPU tests' size (or the configuration file ``c``)."""
+    the CPU tests' size (or the configuration file ``c``), with the
+    per-layer entries of ``bench`` that list a cell of ``name``."""
     root = tmp_path / "checkout"
     for d in ("configs", "cells", "traffic"):
         (root / "chipbench" / d).mkdir(parents=True)
@@ -499,7 +519,7 @@ def _tiny_root(tmp_path, name="qwen3-0.6b", limit=0.05, sizes=None,
     (root / "chipbench/cells/tiny.t.json").write_text(json.dumps(
         {"batch_tokens": 48, "check_requests": 4,
          "limits": {"widest_gap": limit}}))
-    bench = json.loads(json.dumps(BENCH))
+    bench = json.loads(json.dumps(dict(bench, per_layer=listed(bench, name))))
     bench["configs"] = [{"name": "tiny", "source": "-", "reduced": [],
                          "file": "chipbench/configs/tiny.json", "why": "-"}]
     bench["workloads"] = [{"name": "tiny.t", "config": "tiny",
@@ -546,43 +566,71 @@ def test_tiny_run_is_correct(name, tmp_path, capsys, cpu_run):
     _check_tiny_run(_tiny_root(tmp_path, name), capsys, cpu_run)
 
 
-def _split_maps(prompt_lens):
-    """The HLO scope maps of ``_window_trace``'s programs."""
+def _split_maps(prompt_lens, names):
+    """The HLO scope maps of ``_window_trace``'s programs, the decode
+    scan's fusions under the scope ``names`` as ``_scan`` lays them out."""
     maps = {}
     for ii in set(prompt_lens):
         span = f"{trace_reduce.BATCH_PREFIX}{ii}"
         maps[(span, "jit__lambda")] = {"fusion.1": "attention",
                                        "fusion.2": "mlp"}
         maps[(span, "jit__decode_scan")] = {
-            "while.1": "other", "fusion.7": "attention",
-            "fusion.8": "kv_write", "fusion.9": "mlp", "fusion.10": "head"}
+            "while.1": "other", **{op: n for (op, _), n in zip(_scan(names),
+                                                               names)}}
     return maps
 
 
-def test_tiny_traced_run_reads_every_per_layer_metric(tmp_path, capsys,
-                                                      cpu_run, monkeypatch):
+def _check_tiny_traced_run(root, capsys, cpu_run, monkeypatch):
     """``run.main --trace 1``: the window runs under the profiler, and its
     trace (here a fabricated TPU trace of the window's own batches, read
-    0.9 ms early) is reduced, split by scope over the maps compiled after
-    the window, and read by every per-layer metric of the cell."""
+    0.9 ms early, a decode fusion under each scope of the family's
+    ``SCOPES``) is reduced, split by those scopes over the maps compiled
+    after the window, and read by every per-layer metric that the cell
+    lists.  Returns the result line."""
     monkeypatch.setattr(gate, "device_gate", lambda chips: {
         "platform": "tpu", "kind": "TPU v5 lite", "count": chips})
+    cell = run.load_cell("tiny.t", root)
+    names = run.part("programs", cell.conf["program"]).SCOPES
     seen = {}
     real = run.run_window
 
     def run_window(*args, **kw):
         seen["records"], served = real(*args, **kw)
         return seen["records"], served
+
     lens = lambda: [r.prompt_len for r in seen["records"]]
+
+    def program_maps(engine, records, asked):
+        assert asked == names                  # the family's own scopes
+        return _split_maps(lens(), names)
     monkeypatch.setattr(run, "run_window", run_window)
     monkeypatch.setattr(trace_reduce, "load_planes", lambda path:
-                        _window_trace(lens(), early=0.9 * MS))
-    monkeypatch.setattr(scopes, "program_maps",
-                        lambda engine, records: _split_maps(lens()))
-    line, _ = cpu_run(_tiny_root(tmp_path), capsys, extra=("--trace", "1"))
+                        _window_trace(lens(), 0.9 * MS, _scan(names)))
+    monkeypatch.setattr(scopes, "program_maps", program_maps)
+    line, _ = cpu_run(root, capsys, extra=("--trace", "1"))
     assert line["correct"] is True, line["checks"]
     assert line["setup"]["per_layer_unread"] == []
-    assert set(line["metrics"]) == {m["name"] for m in BENCH["per_layer"]}
+    assert set(line["metrics"]) == {m["name"] for m in cell.per_layer}
+    assert line["setup"]["device_lag_s"] == pytest.approx(0.9e-3)
+    split = line["setup"]["split"]
+    assert split["batches_split"] == len(seen["records"])
+    assert sum(split["decode_ms_per_step"].values()) == pytest.approx(
+        split["decode_scan_ms_per_step"])
+    assert line["device"]["window_s"] > line["device"]["busy_s"] > 0
+    return line
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_tiny_traced_run_reads_every_per_layer_metric(name, tmp_path, capsys,
+                                                      cpu_run, monkeypatch):
+    """A traced run of each configuration reads exactly the per-layer
+    metrics whose cells include one of that configuration's, each on its
+    family's own scopes."""
+    line = _check_tiny_traced_run(_tiny_root(tmp_path, name), capsys,
+                                  cpu_run, monkeypatch)
+    assert set(line["metrics"]) == {m["name"] for m in listed(BENCH, name)}
+    if conf(name)["program"] != "dense_gqa":
+        return
     got = {k: v["value"] for k, v in line["metrics"].items()}
     steps = 5                                   # output_len 6
     want = {"decode_attention_ms": 2.0, "decode_kv_write_ms": 0.5,
@@ -592,12 +640,6 @@ def test_tiny_traced_run_reads_every_per_layer_metric(tmp_path, capsys,
         assert got[k] == pytest.approx(v / steps)
     assert got["prefill_attention_share"] == pytest.approx(100 * 2 / 3.1)
     assert got["engine_idle_ms"] == pytest.approx(10 - 3 - 0.1 - 5)
-    assert line["setup"]["device_lag_s"] == pytest.approx(0.9e-3)
-    split = line["setup"]["split"]
-    assert split["batches_split"] == len(seen["records"])
-    assert sum(split["decode_ms_per_step"].values()) == pytest.approx(
-        split["decode_scan_ms_per_step"])
-    assert line["device"]["window_s"] > line["device"]["busy_s"] > 0
 
 
 class _Engine:
@@ -817,6 +859,12 @@ def test_largest_programs_fit_one_v5e(cell, one_chip):
 
 # -- a second family, through new modules alone -----------------------------------------
 SECOND = "second_family"
+# what the second family adds to dense GQA: a scope nested inside ``mlp``,
+# a counter its ``generate`` reports, and a per-layer metric of its own
+# that reads both, listed on its cell alone
+SECOND_SCOPE = "experts"
+SECOND_COUNTER = "experts_reached"
+SECOND_METRIC = "decode_experts_ms_per_expert"
 
 
 class _Family(types.ModuleType):
@@ -835,32 +883,83 @@ class _Family(types.ModuleType):
         return getattr(self._source, key)
 
 
+def _experts_ms_per_expert(rec):
+    """The second family's reader: decode ms per step under its own scope,
+    per expert that a batch reached by the program's counter."""
+    ms = scopes.decode_ms(rec, SECOND_SCOPE)
+    reached = [b.counters.get(SECOND_COUNTER) for b in rec["batches"]]
+    if ms is None or not all(reached):
+        return None
+    return ms * len(reached) / sum(reached)
+
+
 @pytest.fixture
 def second_family(monkeypatch):
     """A configuration whose program, reference and counts are the modules
     ``chipbench.<kind>.second_family``, found only by name: dense GQA
-    behind the interface alone."""
+    behind the interface, with ``SECOND_SCOPE`` in its ``SCOPES``.  Its
+    metric ``SECOND_METRIC`` is ``chipbench.metrics.<name>``, and the
+    benchmark it comes with lists that metric on its cell alone.  Returns
+    (configuration, modules, benchmark)."""
     mods = {}
     for kind, source in (("programs", dense_prog), ("reference", dense_ref),
                          ("counts", dense_counts)):
         mods[kind] = _Family(f"chipbench.{kind}.{SECOND}", source,
                              INTERFACE[kind])
         monkeypatch.setitem(sys.modules, mods[kind].__name__, mods[kind])
+    mods["programs"].SCOPES = dense_prog.SCOPES + (SECOND_SCOPE,)
+    reader = types.ModuleType(f"chipbench.metrics.{SECOND_METRIC}")
+    reader.read = _experts_ms_per_expert
+    monkeypatch.setitem(sys.modules, reader.__name__, reader)
     c = small("qwen3-0.6b")
     c.update(program=SECOND, reference=SECOND, counts=SECOND)
-    return c, mods
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({"name": SECOND, "source": "-", "reduced": [],
+                             "file": "-", "why": "-"})
+    bench["workloads"].append({"name": f"{SECOND}.t", "config": SECOND,
+                               "traffic": "t", "chips": 1, "why": "-"})
+    bench["per_layer"].append({
+        "name": SECOND_METRIC, "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "model decode",
+        "moves": "output_tok_s", "workloads": [f"{SECOND}.t"]})
+    return c, mods, bench
+
+
+def _check_second_traced_run(root, bench, tmp_path, capsys, cpu_run,
+                             monkeypatch):
+    """The second family's traced run reads its own metric from its own
+    scope and counter; a dense cell of the same benchmark does not list
+    it."""
+    import repro.inference.engine as engine
+    real = engine.ServingEngine.generate
+
+    def generate(self, prompts, n, max_len=None):
+        res = real(self, prompts, n, max_len)
+        res.counters = {SECOND_COUNTER: 8}
+        return res
+    monkeypatch.setattr(engine.ServingEngine, "generate", generate)
+    line = _check_tiny_traced_run(root, capsys, cpu_run, monkeypatch)
+    assert set(line["metrics"]) == {SECOND_METRIC}
+    steps = 5                                   # output_len 6
+    assert line["metrics"][SECOND_METRIC]["value"] == pytest.approx(
+        _scan(dense_prog.SCOPES + (SECOND_SCOPE,))[-1][1] / steps / 8)
+    dense = run.load_cell("tiny.t", _tiny_root(tmp_path / "dense",
+                                               bench=bench))
+    assert SECOND_METRIC not in {m["name"] for m in dense.per_layer}
 
 
 @pytest.mark.parametrize("check_", [
     "weight_bytes", "counts", "reference", "gaps", "cell_files",
-    "largest_programs", "tiny_run"])
+    "largest_programs", "tiny_run", "traced_run"])
 def test_second_family_goes_through_by_module_names(
-        check_, second_family, tmp_path, capsys, cpu_run, request):
-    """Every check that is run per configuration or per cell, and a whole
-    run, take a configuration whose modules have other names, through new
+        check_, second_family, tmp_path, capsys, cpu_run, monkeypatch,
+        request):
+    """Every check that is run per configuration or per cell, a whole run
+    and a traced one, take a configuration whose modules have other names,
+    with a scope, a counter and a per-layer metric of its own, through new
     files and entries alone."""
-    c, mods = second_family
-    root = _tiny_root(tmp_path, c=c)
+    c, mods, bench = second_family
+    root = _tiny_root(tmp_path, SECOND, c=c, bench=bench)
     cell = lambda: run.load_cell("tiny.t", root)
     checks = {
         "weight_bytes": lambda: _check_weight_bytes(c),
@@ -871,6 +970,8 @@ def test_second_family_goes_through_by_module_names(
         "largest_programs": lambda: _check_largest_programs(
             cell(), request.getfixturevalue("one_chip")),
         "tiny_run": lambda: _check_tiny_run(root, capsys, cpu_run),
+        "traced_run": lambda: _check_second_traced_run(
+            root, bench, tmp_path, capsys, cpu_run, monkeypatch),
     }
     checks[check_]()
     assert mods["programs"].used and mods["reference"].used

@@ -1,8 +1,9 @@
 """CPU tests of the split by layer and engine phase (``chipbench/scopes.py``).
 
 A small fabricated trace checks the reduction; the tiny dense model's
-compiled programs check that the program names every layer that the
-reduction reads.
+compiled programs check that the program names every layer that its
+family's ``SCOPES`` lists.  Each per-layer check is held to the
+configurations whose cells list the metric.
 """
 from __future__ import annotations
 
@@ -18,6 +19,7 @@ import numpy as np
 import pytest
 
 from chipbench import run, scopes, stats, trace_reduce
+from chipbench.programs import dense_gqa as dense_prog
 from repro.configs import get_config
 from repro.inference import engine as engine_mod
 from repro.inference.engine import ServingEngine
@@ -26,6 +28,14 @@ from repro.models.transformer import Model
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+CONF = {c["name"]: json.loads((ROOT / c["file"]).read_text())
+        for c in BENCH["configs"]}
+# the cells of dense GQA configurations, and the per-layer entries that
+# list one of them: what the dense fabricated records below are read by
+DENSE_CELLS = {w["name"] for w in BENCH["workloads"]
+               if CONF[w["config"]]["program"] == "dense_gqa"}
+DENSE = [m for m in BENCH["per_layer"]
+         if DENSE_CELLS & set(m.get("workloads", DENSE_CELLS))]
 # the per-layer metrics that read the split
 SPLIT = ("decode_attention_ms", "decode_kv_write_ms", "decode_mlp_ms",
          "decode_head_ms", "decode_other_ms", "prefill_attention_share",
@@ -33,7 +43,9 @@ SPLIT = ("decode_attention_ms", "decode_kv_write_ms", "decode_mlp_ms",
 
 
 def test_names_are_the_programs():
-    assert scopes.SCOPES == layers.SCOPES
+    for c in CONF.values():
+        names = run.part("programs", c["program"]).SCOPES
+        assert names and set(names) <= set(layers.SCOPES)
     assert scopes.PHASES == engine_mod.PHASES
     assert scopes.FIRST_TOKEN == engine_mod.FIRST_TOKEN
     assert scopes.HEAD == layers.HEAD
@@ -65,7 +77,7 @@ ENTRY %main.3 (x: f32[8]) -> f32[8] {
 
 
 def test_hlo_scopes_on_a_small_text():
-    got = scopes.hlo_scopes(HLO)
+    got = scopes.hlo_scopes(HLO, dense_prog.SCOPES)
     assert scopes.module_name(HLO) == "jit__decode_scan"
     assert got["sin.1"] == "attention" and got["cos.1"] == "kv_write"
     # a fusion with no op_name: the one scope inside it, else other
@@ -74,8 +86,21 @@ def test_hlo_scopes_on_a_small_text():
     assert got["fusion.3"] == "mlp"            # its own op_name first
     assert got["copy.4"] == "other" and got["x"] == "other"
     assert got["dot.5"] == "head"
-    assert scopes.scope_of("jit(f)/attention/kv_write/dynamic_update_slice") \
-        == "kv_write"
+    assert scopes.scope_of("jit(f)/attention/kv_write/dynamic_update_slice",
+                           dense_prog.SCOPES) == "kv_write"
+
+
+def test_a_listed_scope_nested_in_mlp_takes_its_own_time():
+    """A family that nests a scope of its own inside ``mlp`` and lists it
+    reads that scope there; a family that does not list it reads ``mlp``."""
+    path = "jit(f)/while/body/mlp/experts/dot_general"
+    assert scopes.scope_of(path, dense_prog.SCOPES) == "mlp"
+    assert scopes.scope_of(path, dense_prog.SCOPES + ("experts",)) == \
+        "experts"
+    text = HLO.replace('"jit(f)/mlp/mul"', '"jit(f)/mlp/experts/mul"')
+    assert scopes.hlo_scopes(text, dense_prog.SCOPES)["fusion.3"] == "mlp"
+    assert scopes.hlo_scopes(text, ("mlp", "experts"))["fusion.3"] == \
+        "experts"
 
 
 # -- a fabricated trace ----------------------------------------------------------------
@@ -199,7 +224,7 @@ def test_new_readers_on_the_fabricated_trace():
             "decode_other_ms": 130e-6 / 10,
             "prefill_attention_share": 100 * 60 / 110,
             "engine_idle_ms": 190e-6}
-    entries = [m for m in BENCH["per_layer"] if m["name"] in SPLIT]
+    entries = [m for m in DENSE if m["name"] in SPLIT]
     assert [m["name"] for m in entries] == list(want) == list(SPLIT)
     got = run.read_metrics(entries, rec)
     assert {k: v["value"] for k, v in got.items()} == pytest.approx(want)
@@ -232,7 +257,7 @@ def test_existing_readings_unchanged_by_the_split():
     with_split = _record(scopes.reduce_scopes(_trace(), MAPS))
     for rec in (without, with_split):
         rec.update(spec=spec, counts=counts)
-    entries = [m for m in BENCH["per_layer"] if m["name"] not in SPLIT]
+    entries = [m for m in DENSE if m["name"] not in SPLIT]
     before = run.read_metrics(entries, without)
     assert set(before) == {m["name"] for m in entries}
     assert run.read_metrics(entries, with_split) == before
@@ -250,11 +275,11 @@ def tiny_engine():
 
 def test_compiled_programs_carry_every_scope(tiny_engine):
     recs = [stats.BatchRecord(2, 8, 4, 0.0, 1.0, 0.0, 0.0)]
-    maps = scopes.program_maps(tiny_engine, recs)
+    maps = scopes.program_maps(tiny_engine, recs, dense_prog.SCOPES)
     assert sorted(maps) == [("generate ii=8", "jit__decode_scan"),
                             ("generate ii=8", "jit__lambda")]
     for table in maps.values():
-        assert set(table.values()) == set(scopes.SCOPES) | {scopes.OTHER}
+        assert set(table.values()) == set(dense_prog.SCOPES) | {scopes.OTHER}
 
 
 def test_sample_lands_under_head(tiny_engine):
@@ -266,7 +291,7 @@ def test_sample_lands_under_head(tiny_engine):
     text = tiny_engine._decode_n.lower(
         tiny_engine.params, cache, jax.ShapeDtypeStruct((2, 1), jnp.int32),
         3).compile().as_text()
-    table = scopes.hlo_scopes(text)
+    table = scopes.hlo_scopes(text, dense_prog.SCOPES)
     argmax = [m.group(1) for m in re.finditer(
         r"%([\w.\-]+) = \(f32\[2\]\{0\}, s32\[2\]\{0\}\) reduce\(", text)]
     assert argmax and all(table[n] == scopes.HEAD for n in argmax)
@@ -293,12 +318,13 @@ def test_maps_hold_this_programs_scopes_over_a_cached_program(
         cc.reset_cache()
         with monkeypatch.context() as m:
             m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
-            bare = scopes._program_maps(ServingEngine(Model(cfg), params), recs)
+            bare = scopes._program_maps(ServingEngine(Model(cfg), params),
+                                        recs, dense_prog.SCOPES)
         assert all(set(t.values()) == {scopes.OTHER} for t in bare.values())
         engine = ServingEngine(Model(cfg), params)
         engine.generate(np.zeros((2, 8), np.int32), 4)   # runs the cached
-        maps = scopes.program_maps(engine, recs)
-        assert all(set(t.values()) == set(scopes.SCOPES) | {scopes.OTHER}
+        maps = scopes.program_maps(engine, recs, dense_prog.SCOPES)
+        assert all(set(t.values()) == set(dense_prog.SCOPES) | {scopes.OTHER}
                    for t in maps.values())
     finally:
         for k, v in saved.items():
